@@ -14,11 +14,12 @@ import sys
 import time
 import traceback
 
-from repro.launch.env import pin_runtime
+from repro.launch.env import configure_compile_cache, pin_runtime
 
 # pinned fast runtime (tcmalloc preload when present, quiet XLA logs) —
 # must run before the section modules import jax.
 pin_runtime()
+configure_compile_cache()
 
 from benchmarks import (  # noqa: E402
     bench_adaptive, bench_aggregate, bench_chaos, bench_encode,

@@ -22,6 +22,7 @@ from repro.data import (
     partition_iid, partition_noniid, synthetic_classification,
 )
 from repro.fed import AvailabilityConfig, FedConfig, run_federated
+from repro.launch.env import configure_compile_cache
 from repro.models.paper_models import init_mlp_mnist, mlp_mnist
 from repro.optim import adam
 
@@ -55,6 +56,7 @@ def main():
     ap.add_argument("--adaptive-buffer", action="store_true",
                     help="async: auto-tune buffer_k from the arrival rate")
     args = ap.parse_args()
+    configure_compile_cache()
     if args.mode == "async" and args.deadline > 0:
         ap.error("--deadline applies to --mode sync only "
                  "(the async server never blocks on a round barrier)")

@@ -11,7 +11,7 @@ stacked-scan layer) and the whole tree is encoded by
 
   - ONE ``quantize_pack_segments`` launch for all single-segment leaves of a
     dtype (per-block (denom, Δ) scalars ride in SMEM), plus
-  - one vmapped ``quantize_pack_stacked`` launch per stacked (ndim ≥ 3)
+  - one ``quantize_pack_stacked`` launch per stacked (ndim ≥ 3)
     scan leaf with per-layer scales,
 
 each fusing scale → threshold → ternarize → 2-bit-pack into one HBM read and
@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.core import fttq
 from repro.core.ternary import TernaryTensor, packed_nbytes
+from repro.kernels.ops import use_interpret
 from repro.kernels.quantize_pack import (
     BLOCK_S,
     LANES,
@@ -60,10 +61,6 @@ from repro.kernels.quantize_pack import (
 Pytree = Any
 
 _EPS = 1e-8
-
-
-def _interp(interpret: bool | None) -> bool:
-    return (jax.default_backend() != "tpu") if interpret is None else interpret
 
 
 @dataclasses.dataclass(frozen=True)
@@ -151,9 +148,10 @@ def _encode_flat_group(
 def _encode_stacked_leaf(
     leaf: jax.Array, meta: _Meta, block_s: int, interpret: bool
 ) -> tuple[jax.Array, jax.Array | None]:
-    """One stacked (L, ...) scan leaf through the vmapped kernel: per-layer
-    (denom, Δ) scalars, per-layer packed streams, per-layer w_q where the
-    mode computes it. Ragged layer sizes are repacked host-side."""
+    """One stacked (L, ...) scan leaf, its layers the segments of one
+    launch: per-layer (denom, Δ) scalars, per-layer packed streams,
+    per-layer w_q where the mode computes it. Ragged layer sizes are
+    repacked host-side."""
     n_layers = leaf.shape[0]
     # ONE batched reduction for all layers' denominators (max is
     # order-invariant → bit-identical to the per-layer reference max).
@@ -223,14 +221,14 @@ def _encode_items(
     interpret: bool | None = None,
 ) -> list[TernaryTensor]:
     """Encode a batch of quantizable leaves; one flat-group launch per dtype
-    plus one vmapped launch per stacked leaf, then ONE device→host transfer
+    plus one launch per stacked leaf, then ONE device→host transfer
     for every packed stream and kernel-computed w_q scale of the whole
     batch. Output order matches input."""
     bs = BLOCK_S if block_s is None else block_s
-    interp = _interp(interpret)
+    interp = use_interpret(interpret)
     out: list[TernaryTensor | None] = [None] * len(items)
 
-    # stacked leaves: vmapped per-layer path
+    # stacked leaves: per-layer segments
     stacked_res = [
         (i, *_encode_stacked_leaf(it.leaf, it.meta, bs, interp))
         for i, it in enumerate(items) if it.stacked

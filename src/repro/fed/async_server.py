@@ -74,7 +74,7 @@ from repro.fed.hierarchy import EdgeTier
 from repro.fed.simulation import (
     FedConfig,
     FedResult,
-    _make_local_steps,
+    make_local_steps,
     broadcast_blob,
     dequantize_tree,
     receive_broadcast,
@@ -132,7 +132,7 @@ def run_federated_async(
 ) -> FedResult:
     """Run ``cfg.rounds`` buffered aggregations; see module docstring."""
     rng = np.random.default_rng(cfg.seed)
-    fp_step, qat_step = _make_local_steps(apply_fn, optimizer, cfg)
+    fp_step, qat_step = make_local_steps(apply_fn, optimizer, cfg)
     channel = Channel(cfg.channel, len(clients), seed=cfg.seed + 1)
     avail = make_availability(cfg.availability, len(clients), seed=cfg.seed)
 

@@ -5,7 +5,8 @@ codec crosses a REAL process boundary — and survives that boundary failing.
 ``run_socket_round`` puts the long-lived streaming ``Aggregator`` behind a
 CONCURRENT (threaded accept + per-connection handler) server on a loopback
 socket and spawns N genuine client OS processes (``multiprocessing`` spawn
-context — each child is a fresh interpreter with its own JAX runtime).
+context — each child is a fresh interpreter with its own JAX runtime, held
+to the CPU so that only the server process touches an accelerator).
 
 The conversation (HELLO protocol v2)::
 
@@ -132,6 +133,7 @@ from repro.core.compression import CodecSpec, compress_pytree
 from repro.fed.aggregator import Aggregator
 from repro.fed.attackers import AttackConfig, attacker_ids, poison_blob
 from repro.fed.defense import DefenseConfig, UpdateGate
+from repro.launch.env import configure_compile_cache
 
 Pytree = Any
 
@@ -231,7 +233,9 @@ def _client_main(host: str, port: int, client_id: int, seed: int,
     tail of its UPDATE frame. ``proto=1`` speaks the legacy PR-7
     conversation (single shot, no resume). ``crash_after_frac`` simulates
     a device dying mid-upload: send that fraction of the remaining body,
-    then hard-exit."""
+    then hard-exit. A client stands for an edge device: it computes on the
+    CPU, and the accelerator belongs to the server process alone."""
+    jax.config.update("jax_platforms", "cpu")
     if proto == PROTO_V1:
         _client_main_v1(host, port, client_id, seed, timeout_s)
         return
@@ -358,14 +362,17 @@ def run_inprocess_reference(
     a quorum commit pass the SURVIVING client ids: sorted for sync,
     ``result.arrivals`` for buffered. Under a defense round pass the
     HONEST survivors (quarantined clients never reach the socket
-    aggregator either) and the same ``rule``."""
+    aggregator either) and the same ``rule``. The client updates are
+    derived on the CPU, where the client processes compute them; the mix
+    runs where the server's does."""
     blob = encode_update(global_params)
-    start = decode_update(blob)                 # decode exactly like a client
     ids = list(range(n_clients)) if order is None else list(order)
-    arrivals = [
-        (cid, client_weight(cid), client_update_blob(start, cid, seed))
-        for cid in ids
-    ]
+    with jax.default_device(jax.devices("cpu")[0]):
+        start = decode_update(blob)             # decode exactly like a client
+        arrivals = [
+            (cid, client_weight(cid), client_update_blob(start, cid, seed))
+            for cid in ids
+        ]
     return _mix_arrivals(global_params, arrivals, mode,
                          chunk_c=chunk_c, buffer_k=buffer_k, eta=eta,
                          rule=rule, trim_frac=trim_frac)
@@ -1075,6 +1082,7 @@ def main(argv=None) -> int:
                     help="attacker cohort size (with --attack)")
     ap.add_argument("--attack-seed", type=int, default=11)
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     fault_cfg = None
     quorum_frac = args.quorum_frac

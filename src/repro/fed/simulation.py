@@ -167,7 +167,7 @@ def _ce_loss(apply_fn, params, xb, yb):
     return -jnp.mean(jnp.take_along_axis(logp, yb[:, None], axis=-1))
 
 
-def _make_local_steps(apply_fn, optimizer: Optimizer, cfg: FedConfig):
+def make_local_steps(apply_fn, optimizer: Optimizer, cfg: FedConfig):
     """jit'd per-batch SGD steps for the FP (FedAvg) and QAT (T-FedAvg) paths."""
 
     @jax.jit
@@ -275,6 +275,34 @@ def receive_broadcast(blob: bytes) -> Pytree:
     return dequantize_tree(decode_update(blob))
 
 
+def local_train(
+    client: ClientDataset,
+    start_params: Pytree,
+    cfg: FedConfig,
+    optimizer: Optimizer,
+    fp_step,
+    qat_step,
+    rng: np.random.Generator,
+) -> tuple[Pytree, Pytree | None]:
+    """E local epochs from the decoded broadcast: FTTQ QAT for T-FedAvg,
+    plain SGD for FedAvg. Returns (trained params, trained w_q tree — None
+    for FedAvg)."""
+    params_k = start_params
+    opt_state = optimizer.init(params_k)
+    if cfg.algorithm != "tfedavg":
+        for xb, yb in client.batches(cfg.batch_size, rng, cfg.local_epochs):
+            params_k, opt_state, _ = fp_step(
+                params_k, opt_state, jnp.asarray(xb), jnp.asarray(yb)
+            )
+        return params_k, None
+    wq = fttq_mod.init_wq_tree(params_k, cfg.fttq)
+    for xb, yb in client.batches(cfg.batch_size, rng, cfg.local_epochs):
+        params_k, wq, opt_state, _ = qat_step(
+            params_k, wq, opt_state, jnp.asarray(xb), jnp.asarray(yb)
+        )
+    return params_k, wq
+
+
 def train_client(
     client: ClientDataset,
     start_params: Pytree,
@@ -294,18 +322,14 @@ def train_client(
     ``controller``, the encode instead goes through its per-client rung
     selection + error feedback (``controller.client_payload``); training
     itself is identical either way."""
-    params_k = start_params
-    opt_state = optimizer.init(params_k)
-    wq = None
-    if cfg.algorithm == "tfedavg":
-        wq = fttq_mod.init_wq_tree(params_k, cfg.fttq)
-        for xb, yb in client.batches(cfg.batch_size, rng, cfg.local_epochs):
-            params_k, wq, opt_state, _ = qat_step(
-                params_k, wq, opt_state, jnp.asarray(xb), jnp.asarray(yb)
-            )
-        if controller is not None:
-            return controller.client_payload(client_id, params_k, wq,
-                                             start_params)
+    params_k, wq = local_train(client, start_params, cfg, optimizer,
+                               fp_step, qat_step, rng)
+    if controller is not None:
+        return controller.client_payload(client_id, params_k, wq,
+                                         start_params)
+    if wq is None:
+        payload = params_k
+    else:
         # gate on the RESOLVED upstream spec (not cfg.fused_encode directly)
         # so an explicit cfg.compression's fused_encode flag is honored on
         # this path exactly as broadcast_blob honors the downstream one.
@@ -313,15 +337,6 @@ def train_client(
             params_k, wq, cfg.fttq,
             fused=resolve_compression(cfg).upstream.fused_encode,
         )
-    else:
-        for xb, yb in client.batches(cfg.batch_size, rng, cfg.local_epochs):
-            params_k, opt_state, _ = fp_step(
-                params_k, opt_state, jnp.asarray(xb), jnp.asarray(yb)
-            )
-        if controller is not None:
-            return controller.client_payload(client_id, params_k, None,
-                                             start_params)
-        payload = params_k
     payload, _ = compress_pytree(payload, resolve_compression(cfg).upstream)
     return encode_update(payload)
 
@@ -342,7 +357,7 @@ def run_federated_sync(
     eval_every: int = 10,
 ) -> FedResult:
     rng = np.random.default_rng(cfg.seed)
-    fp_step, qat_step = _make_local_steps(apply_fn, optimizer, cfg)
+    fp_step, qat_step = make_local_steps(apply_fn, optimizer, cfg)
     channel = Channel(cfg.channel, len(clients), seed=cfg.seed + 1)
     avail = make_availability(cfg.availability, len(clients), seed=cfg.seed)
     deadline = cfg.channel.deadline_s if cfg.channel.deadline_s > 0 else float("inf")
@@ -375,7 +390,7 @@ def run_federated_sync(
             "adaptive compression requires aggregation rule 'mean': "
             "mixed-codec rounds have no robust-vote decomposition"
         )
-    up_bytes_per_round = []
+    up_bytes_per_round, down_bytes_per_round = [], []
 
     for r in range(cfg.rounds):
         if ctrl is not None:
@@ -395,6 +410,7 @@ def run_federated_sync(
         # ---- configuration (downstream broadcast, one serialized buffer) -
         blob = broadcast_blob(global_params, cfg)
         down_bytes += len(blob) * len(selected)
+        down_bytes_per_round.append(len(blob) * len(selected))
         start_params = receive_broadcast(blob)
 
         # ---- local training + reporting (upstream) ----------------------
@@ -529,6 +545,7 @@ def run_federated_sync(
         # upstream wire bytes booked per round (client hop + any edge→root
         # hop) — the bytes-to-target-accuracy benches integrate this.
         "upload_bytes_per_round": up_bytes_per_round,
+        "download_bytes_per_round": down_bytes_per_round,
     }
     if ctrl is not None:
         telemetry["controller"] = ctrl.telemetry()

@@ -1,7 +1,9 @@
 """jit'd public wrappers over the Pallas kernels with automatic backend
-dispatch: real Pallas lowering on TPU, interpret=True elsewhere (this
-container is CPU-only — interpret mode executes the kernel body in Python
-for correctness validation; TPU is the performance target).
+dispatch: real Pallas lowering on a TPU, interpret=True elsewhere. The
+tests run on the CPU with interpret kernels (the kernel body evaluated by
+XLA's CPU backend, for correctness only); the compiled kernels run on the
+chip, where ``python chip_smoke.py`` drives them and checks that no kernel
+was interpreted.
 """
 
 from __future__ import annotations
@@ -15,8 +17,16 @@ from repro.kernels import ternary_quantize as _tq
 from repro.kernels import ref as _ref
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def use_interpret(interpret: bool | None = None) -> bool:
+    """An explicit ``interpret`` wins; ``None`` interprets unless the work
+    lands on a TPU — the default device when one is set (``with
+    jax.default_device(...)``), else the default backend."""
+    if interpret is not None:
+        return interpret
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend() != "tpu"
+    return (dev if isinstance(dev, str) else dev.platform) != "tpu"
 
 
 def fttq_apply(theta: jax.Array, t_k: float, *, interpret: bool | None = None):
@@ -24,7 +34,7 @@ def fttq_apply(theta: jax.Array, t_k: float, *, interpret: bool | None = None):
 
     Returns (I_t int8, θ_t, w_q) — w_q initialized at the Prop-4.1 optimum.
     """
-    interp = _use_interpret() if interpret is None else interpret
+    interp = use_interpret(interpret)
     absw = jnp.abs(theta)
     mx = jnp.max(absw) + 1e-8
     inv_scale = 1.0 / mx
@@ -38,19 +48,19 @@ def fttq_apply(theta: jax.Array, t_k: float, *, interpret: bool | None = None):
 
 
 def pack2bit(i_t: jax.Array, *, interpret: bool | None = None) -> jax.Array:
-    interp = _use_interpret() if interpret is None else interpret
+    interp = use_interpret(interpret)
     return _pack.pack2bit(i_t, interpret=interp)
 
 
 def unpack2bit(packed: jax.Array, dtype=jnp.int8, *, interpret: bool | None = None):
-    interp = _use_interpret() if interpret is None else interpret
+    interp = use_interpret(interpret)
     return _pack.unpack2bit(packed, dtype=dtype, interpret=interp)
 
 
 def ternary_matmul(
     x: jax.Array, packed_w: jax.Array, w_q: jax.Array, *, interpret: bool | None = None
 ) -> jax.Array:
-    interp = _use_interpret() if interpret is None else interpret
+    interp = use_interpret(interpret)
     return _mm.ternary_matmul(x, packed_w, w_q, interpret=interp)
 
 

@@ -193,8 +193,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, variant: str = "baselin
     }
     try:
         fn, args, cfg = build_cell(arch, shape_name, mesh, variant)
-        from repro.compat import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(*args)
             t_lower = time.time()
             compiled = lowered.compile()

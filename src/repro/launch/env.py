@@ -21,12 +21,23 @@ Import-order contract: call ``pin_runtime()`` before anything imports
 jax. ``benchmarks/run.py`` does this on its first line; tests do NOT use
 this module (they must see the real single-device CPU host, see
 ``tests/conftest.py``).
+
+``configure_compile_cache()`` turns on JAX's persistent compilation cache
+for the entry points (serving, training, the federated examples, the
+benchmark harness, the socket server and ``chip_smoke.py``). The cache key
+includes the directory, so the path is fixed: ``JAX_COMPILATION_CACHE_DIR``
+when the environment sets it, otherwise ``.jax_cache`` at the root of the
+checkout.
 """
 
 from __future__ import annotations
 
 import os
+import pathlib
 import sys
+
+# <checkout>/src/repro/launch/env.py → <checkout>/.jax_cache
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 # re-exec guard: the env var survives the exec, the module global does not.
 _REEXEC_MARKER = "REPRO_ENV_PINNED"
@@ -93,3 +104,14 @@ def pin_runtime(
             os.execv(sys.executable, [sys.executable] + sys.argv)
         applied["tcmalloc"] = None     # present but not preloaded this run
     return applied
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its one fixed directory
+    and return that directory: ``JAX_COMPILATION_CACHE_DIR`` when set
+    (nothing else is configured in code), else ``DEFAULT_CACHE_DIR``."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(DEFAULT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

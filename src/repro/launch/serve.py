@@ -32,6 +32,7 @@ from repro.comm import ChannelConfig, ClientLink, decode_update, encode_update
 from repro.core import CodecSpec, FTTQConfig, decompress_pytree
 from repro.core import compression as comp
 from repro.kernels.repack import packed_params_from_wire
+from repro.launch.env import configure_compile_cache
 from repro.models.transformer import (
     decode_step, forward, init_cache, init_params, param_count,
 )
@@ -48,7 +49,8 @@ def ternary_deploy(
 ):
     """Compress → serialize → decode the deployment artifact.
 
-    Returns (served_params, wire_bytes, est_download_s, link). With
+    Returns (served_params, blob, est_download_s, link): ``blob`` is the
+    serialized artifact, ``len(blob)`` the edge-checkpoint size. With
     ``packed=False`` the artifact dequantizes to dense arrays (reference
     path); with ``packed=True`` ternary records repack straight into the
     ``(K//4, N)`` kernel layout and stay 2-bit in HBM. ``loss_rate`` runs
@@ -75,14 +77,60 @@ def ternary_deploy(
             1, seed=0,
         )
         chan.links[0] = link   # meter over THIS link, not a fresh draw
-        return served, len(blob), chan.transfer(0, len(blob), "down"), link
-    return served, len(blob), link.transfer_time(len(blob)), link
+        return served, blob, chan.transfer(0, len(blob), "down"), link
+    return served, blob, link.transfer_time(len(blob)), link
+
+
+def packed_logits_gap(cfg, served, blob: bytes, tokens) -> tuple[float, float]:
+    """Correctness receipt of the packed deploy: (max |Δ| between the
+    logits of the packed-kernel ``served`` params and of the dense
+    reference decoded from the same wire ``blob``, max |reference logit|).
+    The reference is a second dense copy of the model, so drop the fp32
+    tree before calling this. Both forwards run at full f32 matmul
+    precision: at a TPU's default precision the dense side alone would
+    round its operands to bf16, and the gap would measure that."""
+    ref = decompress_pytree(decode_update(blob))
+    with jax.default_matmul_precision("highest"):
+        lp, _, _ = forward(cfg, served, tokens)
+        lr, _, _ = forward(cfg, ref, tokens)
+    return (float(jnp.max(jnp.abs(lp - lr))), float(jnp.max(jnp.abs(lr))))
+
+
+def generate(cfg, params, prompts, gen: int, vision=None) -> jax.Array:
+    """Prefill ``prompts`` into a KV cache, then ``gen − 1`` greedy decode
+    steps; prints both timings and returns the (B, gen) generated tokens."""
+    b, s = prompts.shape
+    cache = init_cache(cfg, b, s + gen)
+    t0 = time.time()
+    logits, cache, _ = forward(cfg, params, prompts, vision_embeds=vision,
+                               cache=cache, pos=0)
+    jax.block_until_ready(logits)
+    print(f"prefill: {b}×{s} tokens in {(time.time() - t0) * 1e3:.0f} ms")
+
+    @jax.jit
+    def step(params, tok, cache, pos):
+        return decode_step(cfg, params, tok, cache, pos, vision_embeds=vision)
+
+    tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+    out = [tok]
+    t0 = time.time()
+    for i in range(gen - 1):
+        logits, cache = step(params, tok, cache, s + i)
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        out.append(tok)
+    jax.block_until_ready(tok)
+    dt = time.time() - t0
+    print(f"decode: {gen - 1} steps × batch {b} in {dt * 1e3:.0f} ms "
+          f"({b * (gen - 1) / max(dt, 1e-9):.1f} tok/s)")
+    return jnp.concatenate(out, axis=1)
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="the arch's smoke-size config instead of its "
+                         "published widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -100,6 +148,7 @@ def main():
     args = ap.parse_args()
     if args.packed and not args.ternary:
         raise SystemExit("--packed requires --ternary")
+    configure_compile_cache()
 
     from repro.configs import get_config, get_reduced
 
@@ -116,63 +165,28 @@ def main():
           f"ternary={args.ternary} packed={args.packed}")
     if args.ternary:
         fp_bytes = len(encode_update(params))
-        served, wire_bytes, dl_s, link = ternary_deploy(
+        served, blob, dl_s, link = ternary_deploy(
             params, FTTQConfig(), packed=args.packed,
             residual=args.residual_codec, loss_rate=args.loss_rate,
         )
-        print(f"edge checkpoint: {wire_bytes / 1e6:.2f} MB on the wire "
-              f"(fp32 {fp_bytes / 1e6:.2f} MB, {fp_bytes / wire_bytes:.1f}× "
+        params = served     # the fp32 tree is dropped here
+        print(f"edge checkpoint: {len(blob) / 1e6:.2f} MB on the wire "
+              f"(fp32 {fp_bytes / 1e6:.2f} MB, {fp_bytes / len(blob):.1f}× "
               f"smaller), est. download {dl_s:.1f}s "
               f"@ {link.bandwidth_bytes_s / 1e6:.1f} MB/s")
         if args.packed:
-            # correctness receipt: packed-kernel logits vs the dequantized
-            # reference path (the reference copy exists only for this check;
-            # compression is deterministic, so both deploys see one blob).
-            ref_params, _, _, _ = ternary_deploy(
-                params, FTTQConfig(), packed=False,
-                residual=args.residual_codec,
-            )
             probe = jax.random.randint(
                 jax.random.PRNGKey(9), (2, 8), 0, cfg.vocab_size)
-            lp, _, _ = forward(cfg, served, probe)
-            lr, _, _ = forward(cfg, ref_params, probe)
-            diff = float(jnp.max(jnp.abs(lp - lr)))
-            print(f"packed-vs-dequant logits: max |Δ| = {diff:.2e}")
-        params = served
+            diff, scale = packed_logits_gap(cfg, params, blob, probe)
+            print(f"packed-vs-dequant logits: max |Δ| = {diff:.2e} "
+                  f"(max |logit| {scale:.2e})")
 
     b, s = args.batch, args.prompt_len
     prompts = jax.random.randint(jax.random.PRNGKey(1), (b, s), 0, cfg.vocab_size)
     vision = (jax.random.normal(jax.random.PRNGKey(2),
                                 (b, cfg.n_patches, cfg.d_model)) * 0.02
               if cfg.family == "vlm" else None)
-    max_seq = s + args.gen
-
-    # prefill
-    cache = init_cache(cfg, b, max_seq)
-    t0 = time.time()
-    logits, cache, _ = forward(cfg, params, prompts, vision_embeds=vision,
-                               cache=cache, pos=0)
-    jax.block_until_ready(logits)
-    t_prefill = time.time() - t0
-    print(f"prefill: {b}×{s} tokens in {t_prefill * 1e3:.0f} ms")
-
-    # decode
-    @jax.jit
-    def step(params, tok, cache, pos):
-        return decode_step(cfg, params, tok, cache, pos, vision_embeds=vision)
-
-    tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-    out = [tok]
-    t0 = time.time()
-    for i in range(args.gen - 1):
-        logits, cache = step(params, tok, cache, s + i)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out.append(tok)
-    jax.block_until_ready(tok)
-    dt = time.time() - t0
-    print(f"decode: {args.gen - 1} steps × batch {b} in {dt * 1e3:.0f} ms "
-          f"({b * (args.gen - 1) / max(dt, 1e-9):.1f} tok/s)")
-    gen = jnp.concatenate(out, axis=1)
+    gen = generate(cfg, params, prompts, args.gen, vision)
     print("sample tokens:", gen[0, :12].tolist())
 
 

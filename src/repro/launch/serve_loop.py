@@ -53,6 +53,7 @@ from repro.core.compression import (
 )
 from repro.core.ternary import TernaryTensor
 from repro.kernels.repack import PackedTernary, repack_to_kernel_layout
+from repro.launch.env import configure_compile_cache
 
 Pytree = Any
 
@@ -335,6 +336,7 @@ def main(argv=None) -> int:
     ap.add_argument("--cache-bytes", type=int, default=1 << 24)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     cfg, params = demo_model(args.d_model, args.layers)
     engine = ServeEngine(cfg, params, max_batch=args.max_batch,

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs import get_config, get_reduced
 from repro.data.synthetic import synthetic_tokens, token_batches
+from repro.launch.env import configure_compile_cache
 from repro.models.transformer import ModelConfig, param_count
 from repro.optim import adam, warmup_cosine_schedule
 from repro.train import (
@@ -56,6 +57,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.arch:
         cfg = get_reduced(args.arch)

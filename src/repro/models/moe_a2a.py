@@ -114,8 +114,7 @@ def moe_a2a(
     b, s, d = x.shape
     t = b * s
     xt = x.reshape(t, d)
-    from repro.compat import axis_size
-    n_ep = axis_size(ep_axis)
+    n_ep = jax.lax.axis_size(ep_axis)
     e_loc = n_experts // n_ep
 
     # ---- 1. local routing (router weights are replicated) ----------------

@@ -349,9 +349,7 @@ def _moe_a2a_shardmapped(cfg, mp, x):
             ep_axis=ep, data_axes=bax, wire_dtype=cfg.moe_wire,
         )
 
-    from repro.compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         fn, in_specs=(x_spec, pspecs), out_specs=(x_spec, P()),
         axis_names=set(bax) | {ep}, check_vma=False,
     )(x, mp)

@@ -26,12 +26,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.aggregate import BLOCK_ROWS, packed_weighted_sum
+from repro.kernels.ops import use_interpret
 from repro.kernels.vote import packed_vote_counts
-
-try:  # jax ≥ 0.5 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def _fanin_axis(mesh: Mesh) -> str:
@@ -59,11 +55,11 @@ def _build(c: int, rows: int, block_rows: int, interpret: bool,
         )
         return jax.lax.psum(part, axis)
 
-    # check_rep=False: pallas_call has no replication rule; the psum above
+    # check_vma=False: pallas_call has no replication rule; the psum above
     # establishes the replicated output explicitly.
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         shard, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -88,9 +84,9 @@ def _build_vote(c: int, rows: int, block_rows: int, interpret: bool,
         # same psum merge as the mean path applies.
         return jax.lax.psum(part, axis)
 
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         shard, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -107,7 +103,7 @@ def fanin_vote_counts(
     Same staging contract as ``fanin_weighted_sum``; returns
     (2, 4·R·LANES) fp32 [minus_mass, plus_mass], replicated.
     """
-    interp = (jax.default_backend() != "tpu") if interpret is None else interpret
+    interp = use_interpret(interpret)
     c, rows, _ = stacked.shape
     axis = _fanin_axis(mesh) if mesh is not None else None
     fn = _build_vote(c, rows, block_rows, interp, mesh, axis)
@@ -127,7 +123,7 @@ def fanin_weighted_sum(
     stacked: (C, R, LANES) uint8 flat-packed 2-bit codes; coeffs: (C,) f32.
     Returns the flat fp32 weighted sum (length 4·R·LANES), replicated.
     """
-    interp = (jax.default_backend() != "tpu") if interpret is None else interpret
+    interp = use_interpret(interpret)
     c, rows, _ = stacked.shape
     axis = _fanin_axis(mesh) if mesh is not None else None
     fn = _build(c, rows, block_rows, interp, mesh, axis)

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import fttq
 from repro.models import transformer as tfm
 from repro.optim import Optimizer, apply_updates, clip_by_global_norm
@@ -243,7 +242,7 @@ def make_train_step(
         )
         state_specs = jax.tree_util.tree_map(lambda _: P(), state)
         res_specs = jax.tree_util.tree_map(lambda _: P("pod"), residuals)
-        new_state, new_res, metrics = shard_map(
+        new_state, new_res, metrics = jax.shard_map(
             per_pod_step,
             mesh=mesh,
             in_specs=(state_specs, res_specs, batch_specs),

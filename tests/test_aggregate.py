@@ -276,7 +276,8 @@ def test_sharded_fanin_matches_unsharded():
     import numpy as np, jax, jax.numpy as jnp
     from repro.parallel.fanin import fanin_weighted_sum
     from repro.kernels.aggregate import packed_weighted_sum_ref
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(0)
     st = rng.integers(0, 3, size=(16, 32, 128), dtype=np.uint8)
     for j in range(1, 4):

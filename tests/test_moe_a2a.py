@@ -29,16 +29,16 @@ def test_a2a_matches_gspmd_dropfree():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     import repro.configs as C
-    from repro.compat import set_mesh
     from repro.models.transformer import init_params, forward
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     base = dict(capacity_factor=16.0, mesh_batch_axes=("data",),
                 mesh_ep_axis="model")
     cfg_g = C.get_reduced("qwen3-moe-30b-a3b", moe_impl="gspmd", **base)
     cfg_a = C.get_reduced("qwen3-moe-30b-a3b", moe_impl="a2a", **base)
     params = init_params(cfg_g, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg_g.vocab_size)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lg, _, _ = jax.jit(lambda p, t: forward(cfg_g, p, t))(params, toks)
         la, _, _ = jax.jit(lambda p, t: forward(cfg_a, p, t))(params, toks)
     np.testing.assert_allclose(np.asarray(lg, np.float32),
@@ -54,18 +54,18 @@ def test_a2a_int8_wire_close_and_trains():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     import repro.configs as C
-    from repro.compat import set_mesh
     from repro.models.transformer import init_params, forward
     from repro.train import TrainerConfig, init_train_state, make_train_step
     from repro.optim import adam
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     base = dict(capacity_factor=16.0, mesh_batch_axes=("data",),
                 mesh_ep_axis="model", moe_impl="a2a")
     cfg_bf = C.get_reduced("deepseek-moe-16b", moe_wire="bf16", **base)
     cfg_q8 = C.get_reduced("deepseek-moe-16b", moe_wire="int8", **base)
     params = init_params(cfg_bf, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg_bf.vocab_size)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lb, _, _ = jax.jit(lambda p, t: forward(cfg_bf, p, t))(params, toks)
         lq, _, _ = jax.jit(lambda p, t: forward(cfg_q8, p, t))(params, toks)
     rel = float(jnp.linalg.norm(lb - lq) / (jnp.linalg.norm(lb) + 1e-9))
@@ -77,7 +77,7 @@ def test_a2a_int8_wire_close_and_trains():
     step = make_train_step(cfg_q8, tcfg, opt, mesh)
     batch = {"tokens": toks, "labels": jax.random.randint(
         jax.random.PRNGKey(2), (4, 16), 0, cfg_q8.vocab_size)}
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         js = jax.jit(step)
         s, m0 = js(state, batch)
         for _ in range(4):
@@ -93,16 +93,16 @@ def test_quantized_all_to_all_roundtrip_error():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.models.moe_a2a import quantized_all_to_all
-    mesh = jax.make_mesh((4,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("model",))
     # per-device block (4, 8, 32): dim 0 divisible by the 4-way a2a.
     x = jax.random.normal(jax.random.PRNGKey(0), (16, 8, 32))
 
     def f(x):
         return quantized_all_to_all(x, "model")
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=P("model"),
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("model"),
                             out_specs=P("model"), axis_names={"model"},
                             check_vma=False))(x)
     # tiled a2a permutes blocks between devices; with 1 block/device the

@@ -127,9 +127,9 @@ def test_packed_serve_logits_match_dequantized_path(tiny_lm):
     from repro.models.transformer import decode_step, forward, init_cache
 
     cfg, params = tiny_lm
-    packed, nbytes_p, _, _ = ternary_deploy(params, FTTQConfig(), packed=True)
-    dense, nbytes_d, _, _ = ternary_deploy(params, FTTQConfig(), packed=False)
-    assert nbytes_p == nbytes_d  # same wire artifact feeds both paths
+    packed, blob_p, _, _ = ternary_deploy(params, FTTQConfig(), packed=True)
+    dense, blob_d, _, _ = ternary_deploy(params, FTTQConfig(), packed=False)
+    assert blob_p == blob_d  # same wire artifact feeds both paths
 
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, cfg.vocab_size)
     lp, _, _ = forward(cfg, packed, toks)
@@ -185,3 +185,17 @@ def test_packed_matmul_bad_k_raises():
     p3 = repack_to_kernel_layout(encode_ternary(it3, jnp.float32(1.0)))
     with pytest.raises(ValueError, match="scan over the leading axis"):
         packed_matmul(jnp.ones((2, 16)), p3)
+
+
+def test_packed_logits_gap_against_reference_from_same_blob(tiny_lm):
+    """serve's correctness receipt rebuilds the dense reference from the
+    wire blob alone (the fp32 tree may already be gone) and reports the
+    packed-vs-dequant gap next to the reference logits' scale."""
+    from repro.launch.serve import packed_logits_gap, ternary_deploy
+
+    cfg, params = tiny_lm
+    served, blob, _, _ = ternary_deploy(params, FTTQConfig(), packed=True)
+    toks = jax.random.randint(jax.random.PRNGKey(4), (2, 8), 0, cfg.vocab_size)
+    gap, scale = packed_logits_gap(cfg, served, blob, toks)
+    assert scale > 0
+    assert gap <= 1e-4 * max(scale, 1.0)

@@ -8,22 +8,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# Partial-auto shard_map (manual over "pod", auto over "data"/"model") hits
-# a fatal CHECK in the XLA SPMD partitioner bundled with jax 0.4.x
-# ("Check failed: sharding.IsManualSubgroup()" — the subprocess dies with
-# SIGABRT before producing a result). jax ≥ 0.5 (which exports
-# jax.shard_map at top level) ships the fixed partitioner. Full-manual
-# shard_map (test_moe_a2a, fanin) is unaffected.
-PARTIAL_AUTO_XFAIL = pytest.mark.xfail(
-    condition=not hasattr(jax, "shard_map"),
-    reason="partial-auto shard_map aborts XLA's SPMD partitioner on "
-           "jax 0.4.x (IsManualSubgroup CHECK); needs jax ≥ 0.5",
-)
 
 
 def run_with_devices(code: str, n: int = 8) -> str:
@@ -45,7 +30,8 @@ def test_param_specs_cover_tree():
     import repro.configs as C
     from repro.parallel.sharding import param_specs
     from repro.models.transformer import init_params
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     for arch in C.ARCH_IDS:
         cfg = C.get_config(arch)
         shapes = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
@@ -67,21 +53,20 @@ def test_param_specs_cover_tree():
     assert "SPECS_OK" in run_with_devices(code)
 
 
-@PARTIAL_AUTO_XFAIL
 def test_ternary_allreduce_approximates_mean():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.parallel.collectives import ternary_allreduce
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("pod", "data"))
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 32))
 
     def f(x):
         out, _ = ternary_allreduce(x[0], "pod", residual=None)
         return out
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=P("pod"),
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
                             out_specs=P(), axis_names={"pod"},
                             check_vma=False))(x)
     true_mean = jnp.mean(x, axis=0)
@@ -94,18 +79,17 @@ def test_ternary_allreduce_approximates_mean():
     assert "ALLREDUCE_OK" in run_with_devices(code)
 
 
-@PARTIAL_AUTO_XFAIL
 def test_multipod_compressed_training_converges():
     code = """
     import jax, jax.numpy as jnp
-    from repro.compat import set_mesh
     from repro.models.transformer import ModelConfig
     from repro.train import TrainerConfig, make_train_step, init_train_state
     from repro.optim import adam
     cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
                       vocab_size=128, n_heads=4, n_kv_heads=2, head_dim=16,
                       d_ff=128)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     losses = {}
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 128),
              "labels": jax.random.randint(jax.random.PRNGKey(2), (8, 16), 0, 128)}
@@ -115,7 +99,7 @@ def test_multipod_compressed_training_converges():
         opt = adam(2e-3)
         state = init_train_state(cfg, tcfg, opt, jax.random.PRNGKey(0), n_pods=2)
         step = make_train_step(cfg, tcfg, opt, mesh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             js = jax.jit(step)
             tr = []
             for _ in range(6):
@@ -135,7 +119,6 @@ def test_elastic_remesh_after_pod_loss():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.compat import set_mesh
     from repro.models.transformer import ModelConfig
     from repro.optim import adam
     from repro.train import TrainerConfig, init_train_state, make_train_step
@@ -150,15 +133,16 @@ def test_elastic_remesh_after_pod_loss():
              "labels": jax.random.randint(jax.random.PRNGKey(2), (8, 16), 0, 128)}
 
     # train on the 2-"pod" mesh
-    mesh2 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh2 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     state = init_train_state(cfg, tcfg, opt, jax.random.PRNGKey(0))
-    with set_mesh(mesh2):
+    with jax.set_mesh(mesh2):
         step2 = jax.jit(make_train_step(cfg, tcfg, opt, mesh2))
         state, m2 = step2(state, batch)
 
     # "pod failure": rebuild a 1-pod (4-device) mesh, reshard the WHOLE
     # state (params + optimizer moments + scalars), continue
-    mesh1 = jax.make_mesh((2, 2), ("data", "model"))
+    mesh1 = make_mesh((2, 2), ("data", "model"))
     shard1 = param_shardings(cfg, mesh1)
     host = jax.device_get(state)
     repl = NamedSharding(mesh1, P())
@@ -171,7 +155,7 @@ def test_elastic_remesh_after_pod_loss():
                    "v": elastic_reshard(host.opt_state["v"], shard1)},
         step=jax.device_put(host.step, repl),
     )
-    with set_mesh(mesh1):
+    with jax.set_mesh(mesh1):
         step1 = jax.jit(make_train_step(cfg, tcfg, opt, mesh1))
         state1, m1 = step1(state1, batch)
     assert np.isfinite(float(m1["loss"]))

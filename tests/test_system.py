@@ -81,13 +81,13 @@ def test_reduced_mesh_dryrun_integration():
     import jax, jax.numpy as jnp, json
     from jax.sharding import NamedSharding, PartitionSpec as P
     import repro.configs as C
-    from repro.compat import set_mesh
     from repro.launch.hlo_analysis import analyze_hlo
     from repro.launch.steps import make_decode_step
     from repro.models.transformer import init_params, init_cache
     from repro.parallel.sharding import batch_specs, param_specs
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = C.get_reduced("yi-9b", mesh_batch_axes=("data",),
                         param_dtype="bfloat16", compute_dtype="bfloat16")
     params = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
@@ -107,7 +107,7 @@ def test_reduced_mesh_dryrun_integration():
              "pos": jax.ShapeDtypeStruct((), jnp.int32,
                  sharding=NamedSharding(mesh, P()))}
     step = make_decode_step(cfg)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step, donate_argnums=(1,)).lower(params_sh, batch).compile()
     ma = compiled.memory_analysis()
     r = analyze_hlo(compiled.as_text())
@@ -133,11 +133,9 @@ def test_hlo_analyzer_against_xla_cost_analysis():
     w1 = jax.ShapeDtypeStruct((128, 512), jnp.float32)
     w2 = jax.ShapeDtypeStruct((512, 128), jnp.float32)
     x = jax.ShapeDtypeStruct((256, 128), jnp.float32)
-    from repro.compat import cost_analysis
-
     comp = jax.jit(f).lower(w1, w2, x).compile()
     mine = analyze_hlo(comp.as_text())["flops_per_device"]
-    xla = cost_analysis(comp)["flops"]
+    xla = comp.cost_analysis()["flops"]
     assert abs(mine - xla) / xla < 0.05
 
 
@@ -173,3 +171,31 @@ def test_paper_models_forward():
     logits = resnet_cifar(rp, jnp.ones((2, 32, 32, 3)))
     assert logits.shape == (2, 10)
     assert not bool(jnp.any(jnp.isnan(logits)))
+
+
+def test_compile_cache_dir_from_env_or_fixed_checkout_path(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins; without it the cache sits at one
+    fixed path under the checkout, whatever the cwd or the pid."""
+    from repro.launch.env import configure_compile_cache
+
+    fixed = os.path.join(REPO, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert configure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        monkeypatch.chdir(tmp_path)
+        assert configure_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    # another process, another cwd and pid: the same path
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    out = subprocess.run(
+        [sys.executable, "-c", "from repro.launch.env import "
+         "configure_compile_cache as c; print(c())"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, check=True,
+    )
+    assert out.stdout.strip().splitlines()[-1] == fixed
