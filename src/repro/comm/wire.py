@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.compression import (
     KIND_DOWNCAST,
     KIND_RAW,
@@ -684,45 +685,46 @@ def encode_update(tree: Pytree) -> bytes:
     payload's record kinds (v1 for RAW/TERNARY-only traffic — byte-identical
     to what a v1 encoder produced, so old decoders stay compatible; v2 once
     a downcast/top-k record appears)."""
-    lt = _leaf_types()  # hoisted: rebuilt per call, not per pytree node
-    leaves = jax.tree_util.tree_flatten_with_path(
-        tree, is_leaf=lambda x: isinstance(x, lt)
-    )[0]
-    version = min(SUPPORTED_VERSIONS)
-    codec_lt = tuple(wire_leaf_types())
-    prepared: list = []  # (record prefix: len+path+kind, body bytes | writer)
-    total = _HEADER.size
-    for path, leaf in leaves:
-        p = _PATH_SEP.join(_path_entries(path)).encode("utf-8")
-        rec = _record_for_leaf(leaf, codec_lt)
-        version = max(version, rec.min_version)
-        size, emit = rec.prepared(leaf)
-        pfx = struct.pack("<H", len(p)) + p + struct.pack("<B", rec.kind)
-        total += len(pfx) + size
-        prepared.append((pfx, emit))
-    buf = bytearray(total)
-    view = memoryview(buf)
-    off = _HEADER.size
-    for pfx, emit in prepared:
-        end = off + len(pfx)
-        view[off:end] = pfx
-        off = end
-        if type(emit) is bytes:       # small record: body is the bytes
-            end = off + len(emit)
-            view[off:end] = emit
+    with obs.span("repro.wire.encode"):
+        lt = _leaf_types()  # hoisted: rebuilt per call, not per pytree node
+        leaves = jax.tree_util.tree_flatten_with_path(
+            tree, is_leaf=lambda x: isinstance(x, lt)
+        )[0]
+        version = min(SUPPORTED_VERSIONS)
+        codec_lt = tuple(wire_leaf_types())
+        prepared: list = []  # (record prefix: len+path+kind, body bytes | writer)
+        total = _HEADER.size
+        for path, leaf in leaves:
+            p = _PATH_SEP.join(_path_entries(path)).encode("utf-8")
+            rec = _record_for_leaf(leaf, codec_lt)
+            version = max(version, rec.min_version)
+            size, emit = rec.prepared(leaf)
+            pfx = struct.pack("<H", len(p)) + p + struct.pack("<B", rec.kind)
+            total += len(pfx) + size
+            prepared.append((pfx, emit))
+        buf = bytearray(total)
+        view = memoryview(buf)
+        off = _HEADER.size
+        for pfx, emit in prepared:
+            end = off + len(pfx)
+            view[off:end] = pfx
             off = end
-        else:                         # large record: memcpy writer
-            off = emit(view, off)
-    if off != total:  # pragma: no cover - writer/size contract violation
-        raise WireError(
-            f"record writer emitted {off - _HEADER.size} bytes, "
-            f"sized {total - _HEADER.size}"
+            if type(emit) is bytes:       # small record: body is the bytes
+                end = off + len(emit)
+                view[off:end] = emit
+                off = end
+            else:                         # large record: memcpy writer
+                off = emit(view, off)
+        if off != total:  # pragma: no cover - writer/size contract violation
+            raise WireError(
+                f"record writer emitted {off - _HEADER.size} bytes, "
+                f"sized {total - _HEADER.size}"
+            )
+        _HEADER.pack_into(
+            buf, 0, WIRE_MAGIC, version, 0, len(prepared),
+            zlib.crc32(view[_HEADER.size:]), total - _HEADER.size,
         )
-    _HEADER.pack_into(
-        buf, 0, WIRE_MAGIC, version, 0, len(prepared),
-        zlib.crc32(view[_HEADER.size:]), total - _HEADER.size,
-    )
-    return bytes(buf)
+        return bytes(buf)
 
 
 def _check_header(
@@ -770,24 +772,25 @@ def decode_update(data: bytes) -> Pytree:
 
 
 def _decode_records(data: bytes, *, zero_copy: bool = False) -> list[tuple[str, Any]]:
-    body, n_records, version = _check_header(data)
-    r = _Reader(body, zero_copy=zero_copy)
-    out: list[tuple[str, Any]] = []
-    for _ in range(n_records):
-        path = r.take(r.u16()).decode("utf-8")
-        kind = r.u8()
-        rec = _RECORDS.get(kind)
-        if rec is None:
-            raise WireError(f"unknown record kind {kind}")
-        if version < rec.min_version:
-            raise WireError(
-                f"record kind {rec.name} requires wire v{rec.min_version}, "
-                f"buffer is v{version}"
-            )
-        out.append((path, rec.unpack(r)))
-    if r.pos != len(body):
-        raise WireError(f"{len(body) - r.pos} trailing bytes after last record")
-    return out
+    with obs.span("repro.wire.decode"):
+        body, n_records, version = _check_header(data)
+        r = _Reader(body, zero_copy=zero_copy)
+        out: list[tuple[str, Any]] = []
+        for _ in range(n_records):
+            path = r.take(r.u16()).decode("utf-8")
+            kind = r.u8()
+            rec = _RECORDS.get(kind)
+            if rec is None:
+                raise WireError(f"unknown record kind {kind}")
+            if version < rec.min_version:
+                raise WireError(
+                    f"record kind {rec.name} requires wire v{rec.min_version}, "
+                    f"buffer is v{version}"
+                )
+            out.append((path, rec.unpack(r)))
+        if r.pos != len(body):
+            raise WireError(f"{len(body) - r.pos} trailing bytes after last record")
+        return out
 
 
 def decode_update_leaves(

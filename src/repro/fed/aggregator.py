@@ -53,9 +53,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.comm.wire import decode_update_leaves, tree_from_records
 from repro.core.compression import decode_wire_leaf
 from repro.core.ternary import TernaryTensor
@@ -227,34 +229,35 @@ class Aggregator:
     def add(self, blob: bytes, weight: float) -> None:
         """Decode one client's wire buffer (zero-copy) and buffer/accumulate
         it; a full chunk triggers one fused kernel launch per leaf group."""
-        if weight < 0:
-            raise ValueError(f"client weight must be ≥ 0, got {weight}")
-        # weight 0 (an empty data shard) is tolerated exactly like the
-        # reference: the client rides along contributing nothing.
-        pairs = decode_update_leaves(blob, zero_copy=True)
-        paths = [p for p, _ in pairs]
-        if len(set(paths)) != len(paths):
-            # decode_update would last-wins this; an accumulator would
-            # double-count it — refuse loudly (it is a malformed update).
-            from repro.comm.wire import WireError
+        with obs.span("repro.agg.add"):
+            if weight < 0:
+                raise ValueError(f"client weight must be ≥ 0, got {weight}")
+            # weight 0 (an empty data shard) is tolerated exactly like the
+            # reference: the client rides along contributing nothing.
+            pairs = decode_update_leaves(blob, zero_copy=True)
+            paths = [p for p, _ in pairs]
+            if len(set(paths)) != len(paths):
+                # decode_update would last-wins this; an accumulator would
+                # double-count it — refuse loudly (it is a malformed update).
+                from repro.comm.wire import WireError
 
-            raise WireError("duplicate record paths in client update")
-        if self._paths is None:
-            self._paths = paths
+                raise WireError("duplicate record paths in client update")
+            if self._paths is None:
+                self._paths = paths
+                for path, leaf in pairs:
+                    self._plan_leaf(path, leaf)
+            elif paths != self._paths:
+                raise ValueError(
+                    "client update structure changed mid-aggregation: "
+                    f"{len(paths)} records vs {len(self._paths)}"
+                )
             for path, leaf in pairs:
-                self._plan_leaf(path, leaf)
-        elif paths != self._paths:
-            raise ValueError(
-                "client update structure changed mid-aggregation: "
-                f"{len(paths)} records vs {len(self._paths)}"
-            )
-        for path, leaf in pairs:
-            self._add_leaf(path, leaf, float(weight))
-        self._total_weight += float(weight)
-        self._n_clients += 1
-        self._pending += 1
-        if self._pending >= self.chunk_c:
-            self._flush()
+                self._add_leaf(path, leaf, float(weight))
+            self._total_weight += float(weight)
+            self._n_clients += 1
+            self._pending += 1
+            if self._pending >= self.chunk_c:
+                self._flush()
 
     def _plan_leaf(self, path: str, leaf) -> None:
         if self._dense_rule:
@@ -331,24 +334,25 @@ class Aggregator:
             self._add_fallback(path, leaf, weight)
 
     def _add_fallback(self, path: str, leaf, weight: float) -> None:
-        dense = np.asarray(decode_wire_leaf(leaf))
-        if path not in self._fallback_dtype:
-            # reference promotion: float leaves keep their dtype under a
-            # python-float weight, int leaves promote to float32.
-            self._fallback_dtype[path] = (
-                dense.dtype if jnp.issubdtype(dense.dtype, jnp.floating)
-                else np.dtype(np.float32)
-            )
-        if self.rule == "mean":
-            if path not in self._fallback:
-                self._fallback[path] = np.zeros(dense.shape, np.float32)
-            self._fallback[path] += weight * dense.astype(np.float32)
-            self._fallback_touched.add(path)
-        else:
-            # robust order statistics need the whole per-client sample.
-            self._client_dense.setdefault(path, []).append(
-                (weight, dense.astype(np.float32))
-            )
+        with obs.span("repro.agg.dense"):
+            dense = np.asarray(decode_wire_leaf(leaf))
+            if path not in self._fallback_dtype:
+                # reference promotion: float leaves keep their dtype under a
+                # python-float weight, int leaves promote to float32.
+                self._fallback_dtype[path] = (
+                    dense.dtype if jnp.issubdtype(dense.dtype, jnp.floating)
+                    else np.dtype(np.float32)
+                )
+            if self.rule == "mean":
+                if path not in self._fallback:
+                    self._fallback[path] = np.zeros(dense.shape, np.float32)
+                self._fallback[path] += weight * dense.astype(np.float32)
+                self._fallback_touched.add(path)
+            else:
+                # robust order statistics need the whole per-client sample.
+                self._client_dense.setdefault(path, []).append(
+                    (weight, dense.astype(np.float32))
+                )
 
     # -- kernel launches ---------------------------------------------------
 
@@ -362,8 +366,9 @@ class Aggregator:
         return buf
 
     def _flush(self) -> None:
-        for g in self._groups.values():
-            self._flush_group(g)
+        with obs.span("repro.agg.flush"):
+            for g in self._groups.values():
+                self._flush_group(g)
         self._pending = 0
 
     def _flush_group(self, g: _Group) -> None:
@@ -372,33 +377,32 @@ class Aggregator:
             return
         c_pad = bucket_for(c, self.chunk_c)
         buf = self._buffer(c_pad, g.rows)
-        for i, v in enumerate(g.views):
-            buf[i, :g.nbytes] = v
-            buf[i, g.nbytes:] = 0
-        buf[c:] = 0
-        coeffs = np.zeros((c_pad,), np.float32)
-        coeffs[:c] = g.coeffs
-        stacked = buf.reshape(c_pad, g.rows, LANES)
-        if self.rule == "majority":
-            # a zero-padding BYTE is four code-0 slots (−1 votes); the
-            # zeroed coefficient rows cancel them exactly as in the mean
+        with obs.span("repro.agg.stage"):
+            for i, v in enumerate(g.views):
+                buf[i, :g.nbytes] = v
+                buf[i, g.nbytes:] = 0
+            buf[c:] = 0
+            coeffs = np.zeros((c_pad,), np.float32)
+            coeffs[:c] = g.coeffs
+        with obs.span("repro.agg.transfer"):
+            # the put of the staging buffer may be ZERO-COPY (the CPU backend
+            # aliases aligned numpy memory), and the launch below is async
+            stacked, coeffs = jax.device_put((buf.reshape(c_pad, g.rows, LANES), coeffs))
+        # the launch ends at its block, which has to be there: the in-flight
+        # kernel must be done with the buffer before the next group or chunk
+        # refills it, or it would read torn bytes
+        with obs.span("repro.agg.launch"):
+            # majority: a zero-padding BYTE is four code-0 slots (−1 votes);
+            # the zeroed coefficient rows cancel them exactly as in the mean
             # path, and real clients' tail padding lands past n_elements.
-            out = fanin_vote_counts(
-                stacked, coeffs, mesh=self.mesh,
-                block_rows=self.block_rows, interpret=self.interpret,
-            )
+            fanin = fanin_vote_counts if self.rule == "majority" else fanin_weighted_sum
+            out = fanin(stacked, coeffs, mesh=self.mesh, block_rows=self.block_rows,
+                        interpret=self.interpret)
             out.block_until_ready()
+        obs.count("agg.launches")
+        if self.rule == "majority":
             g.counts = out if g.counts is None else g.counts + out
         else:
-            out = fanin_weighted_sum(
-                stacked, coeffs, mesh=self.mesh, block_rows=self.block_rows,
-                interpret=self.interpret,
-            )
-            # the device_put of the staging buffer may be ZERO-COPY (CPU
-            # backend aliases aligned numpy memory) and the launch is async
-            # — block before the buffer is refilled for the next
-            # group/chunk, or the in-flight kernel would read torn bytes.
-            out.block_until_ready()
             g.partial = out if g.partial is None else g.partial + out
         g.views.clear()
         g.coeffs.clear()
@@ -435,57 +439,58 @@ class Aggregator:
         (Algorithm 2's Σ |D_k|/Σ|D_k| · dequant(payload_k)). With
         ``reset=True`` the instance is immediately reusable for the next
         round (plans + staging buffers survive)."""
-        if self._n_clients == 0:
-            raise ValueError("Aggregator.finalize: no client updates were added")
-        if self._total_weight <= 0:
-            raise ValueError("Aggregator.finalize: total client weight is zero")
-        self._flush()
-        inv = 1.0 / self._total_weight
-        pairs = []
-        for path in self._paths:
-            plan = self._plans[path]
-            if plan.fused and self.rule == "majority":
-                parts = []
-                for s in range(plan.n_segments):
-                    g = self._groups[(path, s)]
-                    counts = np.asarray(g.counts)[:, : g.n_elements]
-                    votes = majority_from_counts(counts, self._total_weight)
-                    vals = np.array([v for v, _ in g.scale_samples], np.float32)
-                    ws = np.array([w for _, w in g.scale_samples], np.float32)
-                    robust_scale = weighted_median(vals, ws)
-                    parts.append(votes.astype(np.float32) * np.float32(robust_scale))
-                flat = parts[0] if len(parts) == 1 else np.concatenate(parts)
-                leaf = jnp.asarray(flat.reshape(plan.shape)).astype(plan.dtype)
-            elif plan.fused:
-                parts = []
-                for s in range(plan.n_segments):
-                    g = self._groups[(path, s)]
-                    # a mixed-codec round may leave a fused group empty
-                    # (every client detoured to the fallback): zero partial.
-                    parts.append(
-                        g.partial[: g.n_elements] if g.partial is not None
-                        else jnp.zeros((g.n_elements,), jnp.float32)
-                    )
-                flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-                if path in self._fallback_touched:
-                    # mixed-codec detours accumulated Σ w·dense here; the
-                    # weighted mean is additive across the two routes.
-                    flat = flat + jnp.asarray(self._fallback[path].reshape(-1))
-                leaf = (flat * inv).reshape(plan.shape).astype(plan.dtype)
-            elif self.rule == "mean":
-                acc = self._fallback[path] * np.float32(inv)
-                leaf = jnp.asarray(acc).astype(self._fallback_dtype[path])
-            else:
-                samples = self._client_dense[path]
-                stack = np.stack([d for _, d in samples])
-                ws = np.array([w for w, _ in samples], np.float32)
-                if self.rule == "trimmed_mean":
-                    acc = trimmed_mean(stack, ws, self.trim_frac)
-                else:  # "median", and the majority rule's dense fallback
-                    acc = weighted_median(stack, ws)
-                leaf = jnp.asarray(acc).astype(self._fallback_dtype[path])
-            pairs.append((path, leaf))
-        out = tree_from_records(pairs)
+        with obs.span("repro.agg.finalize"):
+            if self._n_clients == 0:
+                raise ValueError("Aggregator.finalize: no client updates were added")
+            if self._total_weight <= 0:
+                raise ValueError("Aggregator.finalize: total client weight is zero")
+            self._flush()
+            inv = 1.0 / self._total_weight
+            pairs = []
+            for path in self._paths:
+                plan = self._plans[path]
+                if plan.fused and self.rule == "majority":
+                    parts = []
+                    for s in range(plan.n_segments):
+                        g = self._groups[(path, s)]
+                        counts = np.asarray(g.counts)[:, : g.n_elements]
+                        votes = majority_from_counts(counts, self._total_weight)
+                        vals = np.array([v for v, _ in g.scale_samples], np.float32)
+                        ws = np.array([w for _, w in g.scale_samples], np.float32)
+                        robust_scale = weighted_median(vals, ws)
+                        parts.append(votes.astype(np.float32) * np.float32(robust_scale))
+                    flat = parts[0] if len(parts) == 1 else np.concatenate(parts)
+                    leaf = jnp.asarray(flat.reshape(plan.shape)).astype(plan.dtype)
+                elif plan.fused:
+                    parts = []
+                    for s in range(plan.n_segments):
+                        g = self._groups[(path, s)]
+                        # a mixed-codec round may leave a fused group empty
+                        # (every client detoured to the fallback): zero partial.
+                        parts.append(
+                            g.partial[: g.n_elements] if g.partial is not None
+                            else jnp.zeros((g.n_elements,), jnp.float32)
+                        )
+                    flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+                    if path in self._fallback_touched:
+                        # mixed-codec detours accumulated Σ w·dense here; the
+                        # weighted mean is additive across the two routes.
+                        flat = flat + jnp.asarray(self._fallback[path].reshape(-1))
+                    leaf = (flat * inv).reshape(plan.shape).astype(plan.dtype)
+                elif self.rule == "mean":
+                    acc = self._fallback[path] * np.float32(inv)
+                    leaf = jnp.asarray(acc).astype(self._fallback_dtype[path])
+                else:
+                    samples = self._client_dense[path]
+                    stack = np.stack([d for _, d in samples])
+                    ws = np.array([w for w, _ in samples], np.float32)
+                    if self.rule == "trimmed_mean":
+                        acc = trimmed_mean(stack, ws, self.trim_frac)
+                    else:  # "median", and the majority rule's dense fallback
+                        acc = weighted_median(stack, ws)
+                    leaf = jnp.asarray(acc).astype(self._fallback_dtype[path])
+                pairs.append((path, leaf))
+            out = tree_from_records(pairs)
         if reset:
             self.reset()
         return out

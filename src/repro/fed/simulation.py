@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.comm import Channel, ChannelConfig
 from repro.comm.wire import decode_update, encode_update
 from repro.core import fttq as fttq_mod
@@ -258,21 +259,24 @@ def broadcast_blob(global_params: Pytree, cfg: FedConfig) -> bytes:
     leaves are still raw (biases, norms) — that is where the remaining
     downstream bytes live.
     """
-    dspec = resolve_compression(cfg).downstream
-    if dspec.kind == "ternary":
-        tree = server_requantize(global_params, dspec.fttq,
-                                 fused=dspec.fused_encode)
-        tree, _ = compress_pytree(tree, dspec)  # residual codec on raw leaves
-    else:
-        tree, _ = compress_pytree(global_params, dspec)
-    return encode_update(tree)
+    with obs.span("repro.broadcast"):
+        dspec = resolve_compression(cfg).downstream
+        with obs.span("repro.broadcast.requantize"):
+            if dspec.kind == "ternary":
+                tree = server_requantize(global_params, dspec.fttq,
+                                         fused=dspec.fused_encode)
+                tree, _ = compress_pytree(tree, dspec)  # residual codec on raw leaves
+            else:
+                tree, _ = compress_pytree(global_params, dspec)
+        return encode_update(tree)
 
 
 def receive_broadcast(blob: bytes) -> Pytree:
     """Client side of CONFIGURATION: decode the wire buffer, dequantize.
     Decoded once per broadcast — the result is shared by every recipient of
     the same (immutable) buffer."""
-    return dequantize_tree(decode_update(blob))
+    with obs.span("repro.round.receive"):
+        return dequantize_tree(decode_update(blob))
 
 
 def local_train(
@@ -288,18 +292,19 @@ def local_train(
     plain SGD for FedAvg. Returns (trained params, trained w_q tree — None
     for FedAvg)."""
     params_k = start_params
-    opt_state = optimizer.init(params_k)
-    if cfg.algorithm != "tfedavg":
-        for xb, yb in client.batches(cfg.batch_size, rng, cfg.local_epochs):
-            params_k, opt_state, _ = fp_step(
-                params_k, opt_state, jnp.asarray(xb), jnp.asarray(yb)
-            )
-        return params_k, None
-    wq = fttq_mod.init_wq_tree(params_k, cfg.fttq)
+    qat = cfg.algorithm == "tfedavg"
+    with obs.span("repro.client.init", client=client.client_id):
+        opt_state = optimizer.init(params_k)
+        wq = fttq_mod.init_wq_tree(params_k, cfg.fttq) if qat else None
     for xb, yb in client.batches(cfg.batch_size, rng, cfg.local_epochs):
-        params_k, wq, opt_state, _ = qat_step(
-            params_k, wq, opt_state, jnp.asarray(xb), jnp.asarray(yb)
-        )
+        with obs.span("repro.client.transfer"):
+            xb, yb = jnp.asarray(xb), jnp.asarray(yb)
+        with obs.span("repro.client.step"):
+            if qat:
+                params_k, wq, opt_state, _ = qat_step(params_k, wq, opt_state, xb, yb)
+            else:
+                params_k, opt_state, _ = fp_step(params_k, opt_state, xb, yb)
+        obs.count("client.steps")
     return params_k, wq
 
 
@@ -324,21 +329,22 @@ def train_client(
     itself is identical either way."""
     params_k, wq = local_train(client, start_params, cfg, optimizer,
                                fp_step, qat_step, rng)
-    if controller is not None:
-        return controller.client_payload(client_id, params_k, wq,
-                                         start_params)
-    if wq is None:
-        payload = params_k
-    else:
-        # gate on the RESOLVED upstream spec (not cfg.fused_encode directly)
-        # so an explicit cfg.compression's fused_encode flag is honored on
-        # this path exactly as broadcast_blob honors the downstream one.
-        payload = client_update_payload(
-            params_k, wq, cfg.fttq,
-            fused=resolve_compression(cfg).upstream.fused_encode,
-        )
-    payload, _ = compress_pytree(payload, resolve_compression(cfg).upstream)
-    return encode_update(payload)
+    with obs.span("repro.client.encode"):
+        if controller is not None:
+            return controller.client_payload(client_id, params_k, wq,
+                                             start_params)
+        if wq is None:
+            payload = params_k
+        else:
+            # gate on the RESOLVED upstream spec (not cfg.fused_encode directly)
+            # so an explicit cfg.compression's fused_encode flag is honored on
+            # this path exactly as broadcast_blob honors the downstream one.
+            payload = client_update_payload(
+                params_k, wq, cfg.fttq,
+                fused=resolve_compression(cfg).upstream.fused_encode,
+            )
+        payload, _ = compress_pytree(payload, resolve_compression(cfg).upstream)
+        return encode_update(payload)
 
 
 # --------------------------------------------------------------------------
@@ -393,138 +399,140 @@ def run_federated_sync(
     up_bytes_per_round, down_bytes_per_round = [], []
 
     for r in range(cfg.rounds):
-        if ctrl is not None:
-            ctrl.note_round(r)
-        round_up0 = up_bytes
-        # ---- selection (from the clients ONLINE right now) --------------
-        wait_s = 0.0
-        selected = draw_participants(avail, t_now, n_sel, len(clients), rng)
-        while selected.size == 0:   # fleet empty: wait for the next arrival
-            t_next = avail.next_change(t_now + wait_s)
-            if not np.isfinite(t_next):
-                raise RuntimeError("no client is ever available")
-            wait_s = t_next - t_now
-            selected = draw_participants(avail, t_next, n_sel,
-                                         len(clients), rng)
-
-        # ---- configuration (downstream broadcast, one serialized buffer) -
-        blob = broadcast_blob(global_params, cfg)
-        down_bytes += len(blob) * len(selected)
-        down_bytes_per_round.append(len(blob) * len(selected))
-        start_params = receive_broadcast(blob)
-
-        # ---- local training + reporting (upstream) ----------------------
-        # Download + compute time are known before training; a client whose
-        # link/device alone blows the deadline is dropped WITHOUT paying for
-        # local training (the upload could only add time). The fastest
-        # pre-time client always trains, so no round is ever lost.
-        # The broadcast downloads run SIMULTANEOUSLY and contend for the
-        # server NIC (cfg.channel.server_bandwidth_bytes_s).
-        sel = [int(k) for k in selected]
-        down_times = channel.transfer_concurrent(
-            sel, [len(blob)] * len(sel), "down"
-        )
-        pre = []  # (t_down + t_comp, client_id)
-        for t_down, k in zip(down_times, sel):
-            t_comp = channel.compute_time(k, len(clients[k]) * cfg.local_epochs)
-            pre.append((t_down + t_comp, k))
-        pre.sort()
-
-        arrivals = []  # (total_time, client_id, up_blob) — trained clients
-        for pt, k in pre:
-            if pt > deadline and arrivals:
-                continue            # decidably late; round already safe
-            up_blob = train_client(
-                clients[k], start_params, cfg, optimizer, fp_step, qat_step,
-                rng, controller=ctrl, client_id=k,
-            )
-            if k in attackers:
-                # decode → poison → re-encode: the frame stays wire-valid,
-                # only the content defense can catch it.
-                up_blob = poison_blob(up_blob, cfg.attack, k, round_idx=r)
-            t_up = channel.transfer(k, len(up_blob), "up")
+        with obs.span("repro.round", round=r):
             if ctrl is not None:
-                # the same metered view Channel.log records (TransferEvent):
-                # payload bytes over seconds including retransmissions.
-                ctrl.observe_upload(k, len(up_blob), t_up)
-            arrivals.append((pt + t_up, k, up_blob))
+                ctrl.note_round(r)
+            round_up0 = up_bytes
+            # ---- selection (from the clients ONLINE right now) --------------
+            wait_s = 0.0
+            selected = draw_participants(avail, t_now, n_sel, len(clients), rng)
+            while selected.size == 0:   # fleet empty: wait for the next arrival
+                t_next = avail.next_change(t_now + wait_s)
+                if not np.isfinite(t_next):
+                    raise RuntimeError("no client is ever available")
+                wait_s = t_next - t_now
+                selected = draw_participants(avail, t_next, n_sel,
+                                             len(clients), rng)
 
-        # ---- straggler mitigation: emergent from the channel ------------
-        arrivals.sort(key=lambda a: a[0])
-        survivors = [a for a in arrivals if a[0] <= deadline]
-        if not survivors:            # never lose a round: keep the fastest one
-            survivors = [arrivals[0]]
-        # uploads that arrived but missed the barrier: paid-for waste.
-        # survivors is always a prefix of the time-sorted arrivals.
-        dropped_blob_bytes += sum(
-            len(a[2]) for a in arrivals[len(survivors):]
-        )
-        n_dropped = len(pre) - len(survivors)
-        dropped_hist.append(n_dropped)
-        parts_hist.append(len(survivors))
-        # sync barrier: no drops → the last survivor closes the round; any
-        # drop → the server waited out the full deadline (and, in the
-        # all-dropped fallback, for the fastest client beyond it).
-        last_survivor = max(a[0] for a in survivors)
-        round_times.append(
-            wait_s + (max(deadline, last_survivor) if n_dropped
-                      else last_survivor)
-        )
-        t_now += round_times[-1]
+            # ---- configuration (downstream broadcast, one serialized buffer) -
+            blob = broadcast_blob(global_params, cfg)
+            down_bytes += len(blob) * len(selected)
+            down_bytes_per_round.append(len(blob) * len(selected))
+            start_params = receive_broadcast(blob)
 
-        # ---- ingest gate (content defense) ------------------------------
-        # Survivors cleared framing/CRC/deadline; the gate now vets their
-        # CONTENT. Quarantined uploads were shipped and paid for, so their
-        # bytes are booked as upload AND as quarantine — the third ledger
-        # outcome next to ingested and dropped.
-        if gate is not None:
-            accepted = []
-            for total, k, up_blob in survivors:
-                gated_bytes += len(up_blob)
-                if gate.check(up_blob).ok:
-                    accepted.append((total, k, up_blob))
+            # ---- local training + reporting (upstream) ----------------------
+            # Download + compute time are known before training; a client whose
+            # link/device alone blows the deadline is dropped WITHOUT paying for
+            # local training (the upload could only add time). The fastest
+            # pre-time client always trains, so no round is ever lost.
+            # The broadcast downloads run SIMULTANEOUSLY and contend for the
+            # server NIC (cfg.channel.server_bandwidth_bytes_s).
+            sel = [int(k) for k in selected]
+            down_times = channel.transfer_concurrent(
+                sel, [len(blob)] * len(sel), "down"
+            )
+            pre = []  # (t_down + t_comp, client_id)
+            for t_down, k in zip(down_times, sel):
+                t_comp = channel.compute_time(k, len(clients[k]) * cfg.local_epochs)
+                pre.append((t_down + t_comp, k))
+            pre.sort()
+
+            arrivals = []  # (total_time, client_id, up_blob) — trained clients
+            for pt, k in pre:
+                if pt > deadline and arrivals:
+                    continue            # decidably late; round already safe
+                up_blob = train_client(
+                    clients[k], start_params, cfg, optimizer, fp_step, qat_step,
+                    rng, controller=ctrl, client_id=k,
+                )
+                if k in attackers:
+                    # decode → poison → re-encode: the frame stays wire-valid,
+                    # only the content defense can catch it.
+                    up_blob = poison_blob(up_blob, cfg.attack, k, round_idx=r)
+                t_up = channel.transfer(k, len(up_blob), "up")
+                if ctrl is not None:
+                    # the same metered view Channel.log records (TransferEvent):
+                    # payload bytes over seconds including retransmissions.
+                    ctrl.observe_upload(k, len(up_blob), t_up)
+                arrivals.append((pt + t_up, k, up_blob))
+
+            # ---- straggler mitigation: emergent from the channel ------------
+            arrivals.sort(key=lambda a: a[0])
+            survivors = [a for a in arrivals if a[0] <= deadline]
+            if not survivors:            # never lose a round: keep the fastest one
+                survivors = [arrivals[0]]
+            # uploads that arrived but missed the barrier: paid-for waste.
+            # survivors is always a prefix of the time-sorted arrivals.
+            dropped_blob_bytes += sum(
+                len(a[2]) for a in arrivals[len(survivors):]
+            )
+            n_dropped = len(pre) - len(survivors)
+            dropped_hist.append(n_dropped)
+            parts_hist.append(len(survivors))
+            # sync barrier: no drops → the last survivor closes the round; any
+            # drop → the server waited out the full deadline (and, in the
+            # all-dropped fallback, for the fastest client beyond it).
+            last_survivor = max(a[0] for a in survivors)
+            round_times.append(
+                wait_s + (max(deadline, last_survivor) if n_dropped
+                          else last_survivor)
+            )
+            t_now += round_times[-1]
+
+            # ---- ingest gate (content defense) ------------------------------
+            # Survivors cleared framing/CRC/deadline; the gate now vets their
+            # CONTENT. Quarantined uploads were shipped and paid for, so their
+            # bytes are booked as upload AND as quarantine — the third ledger
+            # outcome next to ingested and dropped.
+            if gate is not None:
+                accepted = []
+                for total, k, up_blob in survivors:
+                    gated_bytes += len(up_blob)
+                    if gate.check(up_blob).ok:
+                        accepted.append((total, k, up_blob))
+                    else:
+                        up_bytes += len(up_blob)
+                        if tier is not None:
+                            tier.note_quarantined(len(up_blob))
+                survivors = accepted
+
+            # ---- aggregation (server decodes the real upstream buffers) -----
+            with obs.span("repro.round.aggregate"):
+                if not survivors:
+                    # every arrival was quarantined: hold the model this round
+                    # (losing a round to a poisoned cohort beats folding it in).
+                    pass
+                elif tier is not None:
+                    # hierarchical: survivors fan into their regional edges; each
+                    # edge ships one (optionally re-quantized) record to the root.
+                    # The edge→root hop is real wire traffic, booked as upload.
+                    for total, k, up_blob in survivors:
+                        up_bytes += len(up_blob)
+                        tier.add(k, up_blob, weight=len(clients[k]))
+                    global_params, fold_info = tier.fold()
+                    up_bytes += fold_info["edge_to_root_bytes"]
+                elif cfg.fused_aggregation:
+                    # streaming fused fan-in: zero-copy record decode into stacked
+                    # packed buffers, one Pallas launch per chunk_c clients — the
+                    # per-client dense trees of the reference loop never exist.
+                    agg = Aggregator(chunk_c=cfg.agg_chunk_c, rule=rule,
+                                     trim_frac=trim_frac)
+                    for total, k, up_blob in survivors:
+                        up_bytes += len(up_blob)
+                        agg.add(up_blob, weight=len(clients[k]))
+                    global_params = agg.finalize()
                 else:
-                    up_bytes += len(up_blob)
-                    if tier is not None:
-                        tier.note_quarantined(len(up_blob))
-            survivors = accepted
+                    updates = []
+                    for total, k, up_blob in survivors:
+                        up_bytes += len(up_blob)
+                        updates.append(TernaryUpdate(
+                            payload=decode_update(up_blob),
+                            n_samples=len(clients[k]),
+                            client_id=k,
+                        ))
+                    global_params = server_aggregate(updates)
 
-        # ---- aggregation (server decodes the real upstream buffers) -----
-        if not survivors:
-            # every arrival was quarantined: hold the model this round
-            # (losing a round to a poisoned cohort beats folding it in).
-            pass
-        elif tier is not None:
-            # hierarchical: survivors fan into their regional edges; each
-            # edge ships one (optionally re-quantized) record to the root.
-            # The edge→root hop is real wire traffic, booked as upload.
-            for total, k, up_blob in survivors:
-                up_bytes += len(up_blob)
-                tier.add(k, up_blob, weight=len(clients[k]))
-            global_params, fold_info = tier.fold()
-            up_bytes += fold_info["edge_to_root_bytes"]
-        elif cfg.fused_aggregation:
-            # streaming fused fan-in: zero-copy record decode into stacked
-            # packed buffers, one Pallas launch per chunk_c clients — the
-            # per-client dense trees of the reference loop never exist.
-            agg = Aggregator(chunk_c=cfg.agg_chunk_c, rule=rule,
-                             trim_frac=trim_frac)
-            for total, k, up_blob in survivors:
-                up_bytes += len(up_blob)
-                agg.add(up_blob, weight=len(clients[k]))
-            global_params = agg.finalize()
-        else:
-            updates = []
-            for total, k, up_blob in survivors:
-                up_bytes += len(up_blob)
-                updates.append(TernaryUpdate(
-                    payload=decode_update(up_blob),
-                    n_samples=len(clients[k]),
-                    client_id=k,
-                ))
-            global_params = server_aggregate(updates)
-
-        up_bytes_per_round.append(up_bytes - round_up0)
+            up_bytes_per_round.append(up_bytes - round_up0)
 
         if (r + 1) % eval_every == 0 or r == cfg.rounds - 1:
             acc, ls = eval_fn(global_params)

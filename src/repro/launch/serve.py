@@ -28,6 +28,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.comm import ChannelConfig, ClientLink, decode_update, encode_update
 from repro.core import CodecSpec, FTTQConfig, decompress_pytree
 from repro.core import compression as comp
@@ -98,31 +99,29 @@ def packed_logits_gap(cfg, served, blob: bytes, tokens) -> tuple[float, float]:
 
 def generate(cfg, params, prompts, gen: int, vision=None) -> jax.Array:
     """Prefill ``prompts`` into a KV cache, then ``gen − 1`` greedy decode
-    steps; prints both timings and returns the (B, gen) generated tokens."""
-    b, s = prompts.shape
-    cache = init_cache(cfg, b, s + gen)
-    t0 = time.time()
-    logits, cache, _ = forward(cfg, params, prompts, vision_embeds=vision,
-                               cache=cache, pos=0)
-    jax.block_until_ready(logits)
-    print(f"prefill: {b}×{s} tokens in {(time.time() - t0) * 1e3:.0f} ms")
+    steps; returns the (B, gen) generated tokens, on the device."""
+    with obs.span("repro.serve.generate"):
+        b, s = prompts.shape
+        cache = init_cache(cfg, b, s + gen)
+        with obs.span("repro.serve.prefill"):
+            logits, cache, _ = forward(cfg, params, prompts, vision_embeds=vision,
+                                       cache=cache, pos=0)
+            jax.block_until_ready(logits)
 
-    @jax.jit
-    def step(params, tok, cache, pos):
-        return decode_step(cfg, params, tok, cache, pos, vision_embeds=vision)
+        @jax.jit
+        def step(params, tok, cache, pos):
+            return decode_step(cfg, params, tok, cache, pos, vision_embeds=vision)
 
-    tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-    out = [tok]
-    t0 = time.time()
-    for i in range(gen - 1):
-        logits, cache = step(params, tok, cache, s + i)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out.append(tok)
-    jax.block_until_ready(tok)
-    dt = time.time() - t0
-    print(f"decode: {gen - 1} steps × batch {b} in {dt * 1e3:.0f} ms "
-          f"({b * (gen - 1) / max(dt, 1e-9):.1f} tok/s)")
-    return jnp.concatenate(out, axis=1)
+        tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        out = [tok]
+        for i in range(gen - 1):
+            with obs.span("repro.serve.step"):
+                logits, cache = step(params, tok, cache, s + i)
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            obs.count("serve.steps")
+            out.append(tok)
+        jax.block_until_ready(tok)
+        return jnp.concatenate(out, axis=1)
 
 
 def main():
@@ -186,7 +185,10 @@ def main():
     vision = (jax.random.normal(jax.random.PRNGKey(2),
                                 (b, cfg.n_patches, cfg.d_model)) * 0.02
               if cfg.family == "vlm" else None)
-    gen = generate(cfg, params, prompts, args.gen, vision)
+    t0 = time.perf_counter()
+    gen = generate(cfg, params, prompts, args.gen, vision).block_until_ready()
+    print(f"generated {b}×{args.gen} tokens in {(time.perf_counter() - t0) * 1e3:.0f} ms "
+          "(prefill, decode and the step's compile)")
     print("sample tokens:", gen[0, :12].tolist())
 
 

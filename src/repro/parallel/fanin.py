@@ -22,7 +22,6 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.aggregate import BLOCK_ROWS, packed_weighted_sum
@@ -107,7 +106,7 @@ def fanin_vote_counts(
     c, rows, _ = stacked.shape
     axis = _fanin_axis(mesh) if mesh is not None else None
     fn = _build_vote(c, rows, block_rows, interp, mesh, axis)
-    return fn(jnp.asarray(stacked), jnp.asarray(coeffs, jnp.float32))
+    return fn(stacked, coeffs)
 
 
 def fanin_weighted_sum(
@@ -120,14 +119,15 @@ def fanin_weighted_sum(
 ) -> jax.Array:
     """Σ_c coeffs[c]·unpack(stacked[c]), C-sharded over ``mesh`` when given.
 
-    stacked: (C, R, LANES) uint8 flat-packed 2-bit codes; coeffs: (C,) f32.
+    stacked: (C, R, LANES) uint8 flat-packed 2-bit codes; coeffs: (C,) f32;
+    host (numpy) arrays are moved to the device by the jitted launch itself.
     Returns the flat fp32 weighted sum (length 4·R·LANES), replicated.
     """
     interp = use_interpret(interpret)
     c, rows, _ = stacked.shape
     axis = _fanin_axis(mesh) if mesh is not None else None
     fn = _build(c, rows, block_rows, interp, mesh, axis)
-    return fn(jnp.asarray(stacked), jnp.asarray(coeffs, jnp.float32))
+    return fn(stacked, coeffs)
 
 
 def fanin_trace_count() -> int:
