@@ -54,6 +54,12 @@ allocation instead of O(records) concatenations. Records registered with
 only the legacy ``pack`` still work: a fallback ``prepare`` materializes
 their body once and copies it in.
 
+Decoding parses the record section IN PLACE: one read-only ``memoryview``
+of the caller's buffer, CRC'd once, fields unpacked from it at a cursor and
+array payloads taken as numpy views of it. Only a mutable input buffer is
+copied (once, before the CRC), and ``decode_update`` copies each array into
+a leaf of its own; the counter ``wire.copied_bytes`` adds up both.
+
 The CRC covers the whole record section; ``decode_update`` raises
 ``WireError`` on magic/version/CRC mismatch, truncation, or any malformed
 record — a corrupted or torn transfer never silently yields wrong weights
@@ -251,42 +257,74 @@ def _topk_delta_prepare(t: "TopKTensor") -> _Prepared:
     return _head_writer(head, values)
 
 
+_U16 = struct.Struct("<H")
+_U32 = struct.Struct("<I")
+_U64 = struct.Struct("<Q")
+
+
 class _Reader:
-    def __init__(self, buf: bytes, zero_copy: bool = False):
+    """A cursor over a record section, parsed in place.
+
+    ``buf`` is a read-only ``memoryview`` of the caller's blob (see
+    ``_check_header``): scalar fields are unpacked from it at the cursor and
+    array payloads are numpy views of it, so parsing copies no payload. In
+    zero-copy mode (the streaming aggregator's ingest) those views are the
+    leaves: read-only numpy arrays aliasing the caller's immutable ``bytes``,
+    no device transfer. Otherwise each array is copied into a jax array of
+    its own, so no leaf aliases the blob or keeps a whole upload alive."""
+
+    def __init__(self, buf: memoryview, zero_copy: bool = False):
         self.buf = buf
         self.pos = 0
-        # zero-copy mode: array payloads come back as numpy views aliasing
-        # ``buf`` (read-only, no device transfer) — the streaming
-        # aggregator's ingest path. Default returns jax arrays as before.
         self.zero_copy = zero_copy
 
-    def arr(self, np_arr: np.ndarray):
-        return np_arr if self.zero_copy else jnp.asarray(np_arr)
-
-    def take(self, n: int) -> bytes:
-        if n < 0 or self.pos + n > len(self.buf):
+    def skip(self, n: int) -> int:
+        """Bounds-check the next ``n`` bytes; return their offset and move
+        the cursor past them."""
+        pos = self.pos
+        if n < 0 or pos + n > len(self.buf):
             raise WireError(
-                f"truncated wire buffer: need {n} bytes at offset {self.pos}, "
-                f"have {len(self.buf) - self.pos}"
+                f"truncated wire buffer: need {n} bytes at offset {pos}, "
+                f"have {len(self.buf) - pos}"
             )
-        out = self.buf[self.pos : self.pos + n]
-        self.pos += n
-        return out
+        self.pos = pos + n
+        return pos
+
+    def take(self, n: int) -> memoryview:
+        pos = self.skip(n)
+        return self.buf[pos:pos + n]
+
+    def text(self, n: int, encoding: str = "ascii") -> str:
+        return str(self.take(n), encoding)
 
     def u8(self) -> int:
-        return self.take(1)[0]
+        return self.buf[self.skip(1)]
 
     def u16(self) -> int:
-        return struct.unpack("<H", self.take(2))[0]
+        return _U16.unpack_from(self.buf, self.skip(2))[0]
+
+    def u32(self) -> int:
+        return _U32.unpack_from(self.buf, self.skip(4))[0]
 
     def u64(self) -> int:
-        return struct.unpack("<Q", self.take(8))[0]
+        return _U64.unpack_from(self.buf, self.skip(8))[0]
 
     def meta(self) -> tuple[str, tuple]:
-        dt = self.take(self.u8()).decode("ascii")
+        dt = self.text(self.u8())
         ndim = self.u8()
-        shape = struct.unpack(f"<{ndim}I", self.take(4 * ndim)) if ndim else ()
-        return dt, tuple(shape)
+        if not ndim:
+            return dt, ()
+        return dt, struct.unpack_from(f"<{ndim}I", self.buf, self.skip(4 * ndim))
+
+    def array(self, dtype: np.dtype, n: int, shape: tuple):
+        """The next ``n`` items of ``dtype`` as an array of ``shape``: the
+        view itself in zero-copy mode, else a jax array of its own."""
+        a = np.frombuffer(self.buf, dtype, n, self.skip(n * dtype.itemsize))
+        a = a.reshape(shape)
+        if self.zero_copy:
+            return a
+        obs.count("wire.copied_bytes", a.nbytes)
+        return jnp.array(a)
 
 
 def _resolve_dtype(dtype: str) -> np.dtype:
@@ -298,15 +336,15 @@ def _resolve_dtype(dtype: str) -> np.dtype:
 
 def _decode_array(r: _Reader) -> jax.Array:
     dtype, shape = r.meta()
-    data = r.take(r.u64())
+    nbytes = r.u64()
     np_dt = _resolve_dtype(dtype)
     n = int(np.prod(shape)) if shape else 1
-    if len(data) != n * np_dt.itemsize:
+    if nbytes != n * np_dt.itemsize:
         raise WireError(
-            f"record data length {len(data)} != {n}×{np_dt.itemsize} "
+            f"record data length {nbytes} != {n}×{np_dt.itemsize} "
             f"for dtype={dtype} shape={shape}"
         )
-    return r.arr(np.frombuffer(data, dtype=np_dt).reshape(shape))
+    return r.array(np_dt, n, shape)
 
 
 # --------------------------------------------------------------------------
@@ -338,17 +376,15 @@ def _decode_ternary_body(r: _Reader) -> TernaryTensor:
     s_dtype, s_shape = r.meta()
     s_np = _resolve_dtype(s_dtype)
     s_n = int(np.prod(s_shape)) if s_shape else 1
-    scale = np.frombuffer(r.take(s_n * s_np.itemsize), dtype=s_np).reshape(s_shape)
-    packed = np.frombuffer(r.take(r.u64()), dtype=np.uint8)
+    scale = r.array(s_np, s_n, s_shape)
+    n_packed = r.u64()
     n = int(np.prod(shape)) if shape else 1
-    if packed.size != (n + 3) // 4:
+    if n_packed != (n + 3) // 4:
         raise WireError(
-            f"packed size {packed.size} inconsistent with logical shape {shape}"
+            f"packed size {n_packed} inconsistent with logical shape {shape}"
         )
-    return TernaryTensor(
-        packed=r.arr(packed), w_q=r.arr(scale),
-        shape=tuple(shape), dtype=dtype,
-    )
+    packed = r.array(np.dtype(np.uint8), n_packed, (n_packed,))
+    return TernaryTensor(packed=packed, w_q=scale, shape=tuple(shape), dtype=dtype)
 
 
 def _downcast_body(t: DowncastTensor) -> bytes:
@@ -357,7 +393,7 @@ def _downcast_body(t: DowncastTensor) -> bytes:
 
 
 def _decode_downcast_body(r: _Reader) -> DowncastTensor:
-    orig = r.take(r.u8()).decode("ascii")
+    orig = r.text(r.u8())
     _resolve_dtype(orig)  # validate before it reaches restore()
     return DowncastTensor(data=_decode_array(r), orig_dtype=orig)
 
@@ -467,7 +503,7 @@ def _topk_delta_body(t: TopKTensor) -> bytes:
 def _decode_topk_delta_body(r: _Reader) -> TopKTensor:
     dtype, shape = r.meta()
     _resolve_dtype(dtype)
-    k = struct.unpack("<I", r.take(4))[0]
+    k = r.u32()
     stream = r.take(r.u64())
     n = int(np.prod(shape)) if shape else 1
     gaps = _varint_unpack(stream, k)
@@ -487,7 +523,8 @@ def _decode_topk_delta_body(r: _Reader) -> TopKTensor:
             f"topk values shape {_np(values).shape} != index count {k}"
         )
     return TopKTensor(
-        indices=r.arr(idx), values=values, shape=tuple(shape), dtype=dtype
+        indices=idx if r.zero_copy else jnp.asarray(idx), values=values,
+        shape=tuple(shape), dtype=dtype,
     )
 
 
@@ -729,9 +766,17 @@ def encode_update(tree: Pytree) -> bytes:
 
 def _check_header(
     data: bytes, expect_records: int | None = None
-) -> tuple[bytes, int, int]:
-    """Validate framing and integrity; returns (record section, n_records,
-    buffer wire version)."""
+) -> tuple[memoryview, int, int]:
+    """Validate framing and integrity; returns (read-only view of the record
+    section, n_records, buffer wire version).
+
+    The view aliases ``data`` when it is immutable ``bytes``. Any other
+    buffer (a ``bytearray``, a writable ``memoryview``) is copied once into
+    ``bytes`` first, so the bytes the CRC covers are the bytes parsed, and a
+    caller that refills its buffer cannot change a leaf decoded from it."""
+    if not isinstance(data, bytes):
+        data = bytes(memoryview(data))
+        obs.count("wire.copied_bytes", len(data))
     if len(data) < _HEADER.size:
         raise WireError(f"buffer too short for header: {len(data)} B")
     magic, version, _flags, n_records, crc, body_len = _HEADER.unpack_from(data)
@@ -741,7 +786,7 @@ def _check_header(
         raise WireError(
             f"wire version {version} not supported (have {SUPPORTED_VERSIONS})"
         )
-    body = data[_HEADER.size :]
+    body = memoryview(data)[_HEADER.size:]
     if len(body) != body_len:
         raise WireError(f"body length {len(body)} != header body_len {body_len}")
     if zlib.crc32(body) != crc:
@@ -777,7 +822,7 @@ def _decode_records(data: bytes, *, zero_copy: bool = False) -> list[tuple[str, 
         r = _Reader(body, zero_copy=zero_copy)
         out: list[tuple[str, Any]] = []
         for _ in range(n_records):
-            path = r.take(r.u16()).decode("utf-8")
+            path = r.text(r.u16(), "utf-8")
             kind = r.u8()
             rec = _RECORDS.get(kind)
             if rec is None:
@@ -799,7 +844,11 @@ def decode_update_leaves(
     """Batched record decode: the flat (path, leaf) list in record order,
     WITHOUT rebuilding containers — the streaming aggregator consumes records
     straight off the buffer. With ``zero_copy=True``, array payloads are
-    read-only numpy views aliasing ``data`` (no copy, no device transfer);
+    read-only numpy views into the caller's blob (no copy, no device
+    transfer): they alias ``data`` itself when it is immutable ``bytes``,
+    and a mutable buffer is copied once first, so refilling it later cannot
+    change them. Each view keeps the whole blob alive. With
+    ``zero_copy=False`` every array is a jax array of its own.
     ``tree_from_records`` rebuilds the pytree when one is needed."""
     try:
         return _decode_records(data, zero_copy=zero_copy)

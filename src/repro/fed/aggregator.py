@@ -3,8 +3,9 @@
 ``Aggregator`` replaces the dequantize-every-client Python loop
 (``core.tfedavg.server_aggregate`` stays as the list-based REFERENCE): wire
 blobs stream in one at a time (``add``), their ternary records are decoded
-ZERO-COPY (numpy views straight off the buffer, no per-client device
-transfer) into reusable stacked ``(chunk, R, LANES)`` uint8 buffers, and
+ZERO-COPY (read-only numpy views into the caller's immutable ``bytes`` blob;
+a mutable buffer is copied once; no per-client device transfer), staged at
+each flush into reusable stacked ``(chunk, R, LANES)`` uint8 buffers, and
 every full chunk is folded into the running dense sum by ONE launch of the
 fused Pallas kernel (``kernels.aggregate.packed_weighted_sum``, C-shardable
 over a mesh via ``parallel.fanin``). ``finalize`` flushes the remainder and
@@ -228,7 +229,11 @@ class Aggregator:
 
     def add(self, blob: bytes, weight: float) -> None:
         """Decode one client's wire buffer (zero-copy) and buffer/accumulate
-        it; a full chunk triggers one fused kernel launch per leaf group."""
+        it; a full chunk triggers one fused kernel launch per leaf group.
+
+        The pending ternary records are views into ``blob`` itself when it is
+        ``bytes`` (each keeps the whole blob alive until the chunk flushes);
+        any other buffer is copied once, so the caller may refill it."""
         with obs.span("repro.agg.add"):
             if weight < 0:
                 raise ValueError(f"client weight must be ≥ 0, got {weight}")

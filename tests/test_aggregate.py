@@ -154,6 +154,23 @@ def test_aggregator_weight_scale_invariance():
     _assert_trees_close(outs[0], outs[1], atol=1e-5)
 
 
+def test_aggregator_refilled_bytearray_matches_bytes():
+    """A caller that reuses one ``bytearray`` for every upload, refilling it
+    with the next client's bytes after each ``add``, folds exactly what the
+    unmutated ``bytes`` fold: nothing pending aliases the reused buffer."""
+    blobs = [encode_update(_client_payload(jax.random.PRNGKey(c)))
+             for c in range(3)]
+    assert len({len(b) for b in blobs}) == 1
+    ref, agg = Aggregator(chunk_c=8), Aggregator(chunk_c=8)
+    buf = bytearray(len(blobs[0]))
+    for i, b in enumerate(blobs):
+        ref.add(b, weight=10 + i)
+        buf[:] = b
+        agg.add(buf, weight=10 + i)
+    buf[:] = blobs[0]       # the next round's first upload, before finalize
+    _assert_trees_close(ref.finalize(), agg.finalize(), atol=0.0)
+
+
 def test_aggregator_single_client_is_dequant():
     payload = _client_payload(jax.random.PRNGKey(99))
     agg = Aggregator(chunk_c=16)

@@ -9,10 +9,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.comm import (
     WireError, decode_update, encode_update, update_nbytes,
 )
-from repro.comm.wire import _HEADER, WIRE_MAGIC
+from repro.comm.wire import (
+    _HEADER, WIRE_MAGIC, decode_update_leaves, tree_from_records,
+)
 from repro.core import FTTQConfig
 from repro.core import fttq as F
 from repro.core.compression import wire_nbytes
@@ -157,6 +160,59 @@ def test_truncation_and_magic_and_version_rejected():
         decode_update(bad)
     with pytest.raises(WireError):
         decode_update(b"")
+
+
+# --------------------------------------------------------------------------
+# In-place decode: what aliases the caller's buffer, and what is copied.
+# --------------------------------------------------------------------------
+
+
+def _leaf_arrays(pairs):
+    out = []
+    for _, leaf in pairs:
+        out += [leaf.packed, leaf.w_q] if isinstance(leaf, TernaryTensor) else [leaf]
+    return out
+
+
+@pytest.mark.parametrize("zero_copy", [True, False])
+@pytest.mark.parametrize("kind", ["bytes", "bytearray"])
+def test_decode_aliases_only_an_immutable_blob(kind, zero_copy, tmp_path):
+    """Zero-copy leaves of a ``bytes`` blob are views of it and nothing is
+    copied (``wire.copied_bytes`` 0); every other decode owns its memory, so
+    refilling a ``bytearray`` leaves decoded leaves as they were; a flipped
+    bit in a record body is refused either way."""
+    rng = np.random.default_rng(5)
+    codes = jnp.asarray(rng.integers(-1, 2, (64, 33)), jnp.int8)
+    blob = encode_update({
+        "w": encode_ternary(codes, jnp.float32(0.25)),
+        "b": jnp.asarray(rng.standard_normal(257), jnp.float32),
+    })
+    buf = blob if kind == "bytes" else bytearray(blob)
+    obs.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        pairs = decode_update_leaves(buf, zero_copy=zero_copy)
+        copied = obs.records()["counters"].get("wire.copied_bytes", 0)
+    finally:
+        jax.profiler.stop_trace()
+        obs.clear()
+    arrays = _leaf_arrays(pairs)
+    aliased = kind == "bytes" and zero_copy
+    u8 = np.frombuffer(buf, np.uint8)
+    assert [np.shares_memory(np.asarray(a), u8) for a in arrays] == [aliased] * 3
+    own = 0 if zero_copy else sum(np.asarray(a).nbytes for a in arrays)
+    assert copied == (0 if kind == "bytes" else len(blob)) + own
+    before = [np.array(a) for a in arrays]
+    if kind == "bytearray":
+        buf[:] = bytes(len(buf))
+    for a, b in zip(arrays, before):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    assert_trees_bitexact(tree_from_records(pairs), decode_update(blob))
+    bad = bytearray(blob)
+    bad[len(blob) - 100] ^= 0x10       # in "w"'s packed codes, the last record
+    with pytest.raises(WireError, match="CRC32"):
+        decode_update_leaves(bad if kind == "bytearray" else bytes(bad),
+                             zero_copy=zero_copy)
 
 
 # --------------------------------------------------------------------------
